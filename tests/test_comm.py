@@ -1,50 +1,26 @@
-"""Runner for the multi-device compressed-collective suite.
-
-The suite needs 8 forced host devices, which must be set before jax
-initializes — so it runs in subprocesses (the main pytest process keeps
-the real 1-device view, per the project convention).  The suite is split
-into two halves so each subprocess stays well inside its timeout and
-the two can shard across pytest-xdist workers in CI:
-
-  * legacy half — ledger / bitexact / chunked wire + the flat ring
-    transport (all_reduce / all_gather, carries, backends);
-  * family half — the PR 4 additions: ring reduce_scatter, ring
-    all_to_all, the hierarchical two-axis ring and the MoE a2a
-    dispatch wire.
-"""
-import os
-import pathlib
-import subprocess
-import sys
+"""The 8-device comm suite's endpoint transports (ledger, bitexact,
+streaming chunked, the other collectives) and the lifecycle epoch
+agreement, one subprocess per class (see `_comm_runner.py`); and the
+check that every class of the suite has an outer item."""
+import ast
 
 import pytest
-
-SUITE = pathlib.Path(__file__).parent / "_comm_suite.py"
-
-_FAMILY = ("TestRingReduceScatter or TestRingAllToAll "
-           "or TestHierarchicalRing or TestMoEDispatchA2A")
-
-# The two longest tier-1 items (full multi-device collective suites in
-# subprocesses); CI runs the slow marks in their own sharded job.
-pytestmark = pytest.mark.slow
+from _comm_runner import (ENDPOINT_ITEMS, FAMILY_ITEMS, RING_ITEMS, SUITE,
+                          params, run_suite)
 
 
-def _run_suite(select: str) -> None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env["PYTHONPATH"] = str(pathlib.Path(__file__).parents[1] / "src")
-    proc = subprocess.run([sys.executable, str(SUITE), "-k", select],
-                          env=env, capture_output=True, text=True,
-                          timeout=1800)
-    assert proc.returncode == 0, (
-        f"comm suite (-k {select!r}) failed\nstdout:\n{proc.stdout}\n"
-        f"stderr:\n{proc.stderr}")
+@pytest.mark.slow
+@pytest.mark.parametrize("select,timeout", params(ENDPOINT_ITEMS))
+def test_comm_suite_8_devices(select, timeout):
+    run_suite(select, timeout)
 
 
-def test_comm_suite_8_devices():
-    _run_suite(f"not ({_FAMILY})")
-
-
-def test_comm_suite_ring_family_8_devices():
-    _run_suite(_FAMILY)
+def test_every_suite_class_has_an_item():
+    tree = ast.parse(SUITE.read_text())
+    classes = {node.name for node in tree.body
+               if isinstance(node, ast.ClassDef)
+               and node.name.startswith("Test")}
+    selected = {select.split()[0]
+                for items in (ENDPOINT_ITEMS, RING_ITEMS, FAMILY_ITEMS)
+                for select, _ in items.values()}
+    assert selected == classes

@@ -9,8 +9,6 @@ import pathlib
 import subprocess
 import sys
 
-import pytest
-
 ROOT = pathlib.Path(__file__).parents[1]
 
 
@@ -21,13 +19,12 @@ def _run_dryrun(tmp_path, arch, shape):
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--out", str(out)],
-        env=env, capture_output=True, text=True, timeout=900,
+        env=env, capture_output=True, text=True, timeout=120,
         cwd=str(ROOT))
     assert proc.returncode == 0, proc.stdout + proc.stderr
     return json.load(open(out))
 
 
-@pytest.mark.slow
 class TestDryrunLaunch:
     def test_decode_combo_produces_roofline_record(self, tmp_path):
         recs = _run_dryrun(tmp_path, "mamba2-780m", "decode_32k")

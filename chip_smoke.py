@@ -117,7 +117,8 @@ def phase_kernels(seed: int, n: int = KERNEL_SYMBOLS) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.core.encoder import encode_chunked_jit
+    from repro.core.encoder import (_chunk_rows, _pack_rows_xla,
+                                    encode_chunked_jit)
     from repro.kernels import bitpack, ref
     from repro.kernels.encode import encode_lookup_pallas
     from repro.kernels.histogram import histogram256_pallas
@@ -135,12 +136,18 @@ def phase_kernels(seed: int, n: int = KERNEL_SYMBOLS) -> dict:
     check(bool(jnp.array_equal(codes, wc)) and bool(jnp.array_equal(lens, wl))
           and int(bits) == int(wb), "LUT-encode kernel != gather oracle")
     words, block_bits = bitpack.pack_blocks_pallas(codes, lens)
-    want_w, want_b = encode_chunked_jit(
-        sym, jnp.asarray(book.codes), jnp.asarray(book.lengths),
-        chunk=bitpack.BLOCK)
+    book_codes, book_lens = jnp.asarray(book.codes), jnp.asarray(book.lengths)
+    want_w, want_b = _pack_rows_xla(
+        *_chunk_rows(sym, book_codes, book_lens, bitpack.BLOCK),
+        bitpack.CAP_WORDS)
     check(bool(jnp.array_equal(words, want_w))
           and bool(jnp.array_equal(block_bits, want_b)),
-          "bit-pack kernel != encode_chunked_jit")
+          "bit-pack kernel != scatter-add packing")
+    enc_w, enc_b = encode_chunked_jit(sym, book_codes, book_lens,
+                                      chunk=bitpack.BLOCK)
+    check(bool(jnp.array_equal(enc_w, want_w))
+          and bool(jnp.array_equal(enc_b, want_b)),
+          "encode_chunked_jit != scatter-add packing")
     return {"symbols": n, "coded_bits": int(bits)}
 
 

@@ -38,7 +38,7 @@ __all__ = [
     "chunk_counts_for", "concat_chunks",
     "encode_chunked_jit", "decode_chunks_jit", "recode_chunks_jit",
     "decode_chunks_multisym_jit", "multisym_table_args", "DECODE_BACKENDS",
-    "encode_chunked", "decode_chunked", "decode_dispatch",
+    "encode_chunked", "decode_chunked", "decode_dispatch", "pack_path",
 ]
 
 # Per-call symbol cap so bit offsets fit comfortably in uint32 cumsums.
@@ -111,15 +111,53 @@ class EncodeResult:
 # --------------------------------------------------------------------------
 # jit bit-packing encoder
 # --------------------------------------------------------------------------
+def pack_path(width: int, backend: str) -> str:
+    """How ``_pack_rows`` places codeword parts into words, for rows of
+    ``width`` codes on ``backend``: ``"kernel"`` — the Mosaic bit-pack
+    kernel (``kernels.bitpack``) — where the rows are its BLOCK wide and
+    the backend compiles it (a TPU); ``"xla"`` — two scatter-adds —
+    everywhere else.  Both give the same words, bit for bit."""
+    from ..kernels.bitpack import BLOCK
+    from ..kernels.platform import compiles_with_mosaic
+    return ("kernel" if width == BLOCK and compiles_with_mosaic(backend)
+            else "xla")
+
+
 def _pack_rows(v: jnp.ndarray, l: jnp.ndarray, cap: int
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Shared bit-pack core: per-row MSB-first packing via two masked shifts.
+    """Shared bit-pack core: per-row MSB-first packing.
 
     v: (NB, C) uint32 right-aligned codewords; l: (NB, C) uint32 lengths
     (0 ⇒ the slot contributes no bits).  Returns (words (NB, cap) uint32,
-    bits (NB,) int32).  A codeword of length ≤16 starting at bit offset o
-    spans at most two 32-bit words; high/low parts assemble via
-    scatter-add — fields are disjoint so add ≡ or.
+    bits (NB,) int32), through the path ``pack_path`` picks for the
+    backend tracing the call.
+    """
+    from ..kernels.bitpack import CAP_WORDS
+    if (cap <= CAP_WORDS
+            and pack_path(v.shape[1], jax.default_backend()) == "kernel"):
+        return _pack_rows_kernel(v, l, cap)
+    return _pack_rows_xla(v, l, cap)
+
+
+def _pack_rows_kernel(v: jnp.ndarray, l: jnp.ndarray, cap: int, *,
+                      interpret: bool | None = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``_pack_rows`` by the bit-pack kernel: rows of ``bitpack.BLOCK``
+    codes are its blocks, and ``cap`` ≤ its ``CAP_WORDS`` (codes ≤ 16
+    bits) words hold every row's bits."""
+    from ..kernels.bitpack import pack_blocks_pallas
+    words, bits = pack_blocks_pallas(v.reshape(-1), l.reshape(-1),
+                                     interpret=interpret)
+    return words[:, :cap], bits
+
+
+def _pack_rows_xla(v: jnp.ndarray, l: jnp.ndarray, cap: int
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``_pack_rows`` by two masked shifts and two scatter-adds.
+
+    A codeword of length ≤16 starting at bit offset o spans at most two
+    32-bit words; high/low parts assemble via scatter-add — fields are
+    disjoint so add ≡ or.
     """
     nb = v.shape[0]
     if v.shape[1] == 0:                              # empty stream
@@ -265,11 +303,19 @@ def encode_chunked_jit(symbols: jnp.ndarray, codes: jnp.ndarray,
 
     Same bitfield math as ``encode_jit`` applied per chunk row: pad
     positions get length 0 (and code 0) so they contribute no bits.
-    Pure jnp — safe under jit/shard_map; bit-identical to the Pallas
-    ``pack_blocks_pallas`` kernel for chunk == bitpack.BLOCK.
+    Safe under jit/shard_map; at chunk == bitpack.BLOCK on a TPU the
+    ``pack_blocks_pallas`` kernel packs the rows (``pack_path``), with
+    the same words.
 
     Returns (block_words (NB, cap) uint32, block_bits (NB,) int32).
     """
+    v, l = _chunk_rows(symbols, codes, lengths, chunk)
+    return _pack_rows(v, l, chunk_capacity_words(chunk, max_len))
+
+
+def _chunk_rows(symbols, codes, lengths, chunk: int):
+    """A flat stream's (NB, chunk) code and length rows; pad slots of the
+    tail chunk get code 0 and length 0."""
     n = symbols.shape[0]
     if n > _MAX_SYMBOLS:
         raise ValueError(f"chunk too large: {n} > {_MAX_SYMBOLS}")
@@ -278,9 +324,13 @@ def encode_chunked_jit(symbols: jnp.ndarray, codes: jnp.ndarray,
     sym = jnp.pad(symbols.astype(jnp.int32), (0, pad)).reshape(nb, chunk)
     valid = (jnp.arange(chunk, dtype=jnp.int32)[None, :]
              + jnp.arange(nb, dtype=jnp.int32)[:, None] * chunk) < n
+    return _masked_rows(sym, valid, codes, lengths)
+
+
+def _masked_rows(sym, valid, codes, lengths):
     v = codes[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
     l = lengths[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
-    return _pack_rows(v, l, chunk_capacity_words(chunk, max_len))
+    return v, l
 
 
 @partial(jax.jit, static_argnames=("max_len",))
@@ -300,13 +350,17 @@ def recode_chunks_jit(sym_blocks: jnp.ndarray, chunk_counts: jnp.ndarray,
     sym_blocks: (NB, chunk) uint8/int32; chunk_counts: (NB,) int32.
     Returns (block_words (NB, cap) uint32, block_bits (NB,) int32).
     """
-    nb, chunk = sym_blocks.shape
-    sym = sym_blocks.astype(jnp.int32)
+    v, l = _block_rows(sym_blocks, chunk_counts, codes, lengths)
+    return _pack_rows(v, l, chunk_capacity_words(sym_blocks.shape[1], max_len))
+
+
+def _block_rows(sym_blocks, chunk_counts, codes, lengths):
+    """Blocked symbols' code and length rows; slots past each chunk's
+    count get code 0 and length 0."""
+    chunk = sym_blocks.shape[1]
     valid = (jnp.arange(chunk, dtype=jnp.int32)[None, :]
              < chunk_counts.astype(jnp.int32)[:, None])
-    v = codes[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
-    l = lengths[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
-    return _pack_rows(v, l, chunk_capacity_words(chunk, max_len))
+    return _masked_rows(sym_blocks.astype(jnp.int32), valid, codes, lengths)
 
 
 @partial(jax.jit, static_argnames=("chunk", "max_len"))

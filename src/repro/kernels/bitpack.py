@@ -18,10 +18,15 @@ running per-row offset.  A code of ≤ 16 bits at offset o spans word
 ``o >> 5`` (its high part) and possibly the next (its low part).  There
 is no vector scatter on the TPU, so the "scatter" is a matmul too: for
 each row, the byte planes of the high and low parts, (8, 128), are
-contracted over the row's 128 codes with a (words, 128) one-hot of
-their word index.  Codes own disjoint bit fields, so every byte of
-every word is a sum of disjoint fields ≤ 255 — exact in bf16 inputs and
-f32 accumulation.  The low parts land one word later (a lane roll).
+contracted over the row's 128 codes with a (WIN, 128) one-hot of their
+word index within a window.  A row's 128 codes of ≤ 16 bits touch at
+most 65 words from ``run >> 5`` on (``run``: the bits before the row),
+so a window of WIN = 256 words from the 128-aligned ``base = (run >> 5)
+& ~127`` holds them all, and ``base + WIN ≤ CAP_PAD`` for every row of a
+block; each row's (8, WIN) product is added into the block's
+accumulator at ``base``.  Codes own disjoint bit fields, so every byte
+of every word is a sum of disjoint fields ≤ 255 — exact in bf16 inputs
+and f32 accumulation.  The low parts land one word later (a lane roll).
 
 Per-block capacity is BLOCK × MAX_CODE_LEN bits; the block's true bit
 count rides in a side output so the merge drops the slack.
@@ -44,19 +49,21 @@ CAP_WORDS = BLOCK * MAX_CODE_LEN // 32 + 1      # +1 pad word
 LANES = 128
 ROWS = BLOCK // LANES                           # 16 rows of 128 codes
 CAP_PAD = -(-CAP_WORDS // LANES) * LANES        # lane-aligned word slab
+WIN = 2 * LANES                                 # words one row can touch
 G = 8                                           # blocks per grid step
 
 _srl = jax.lax.shift_right_logical
 
 
-def _pack_kernel(codes_ref, lens_ref, words_ref, bits_ref, incl_ref, *,
-                 g_step: int):
+def _pack_kernel(codes_ref, lens_ref, words_ref, bits_ref, incl_ref,
+                 acc_ref, *, g_step: int):
     """Pack g_step blocks.  codes/lens: (g_step·ROWS, LANES) int32
-    (len 0 → pad); incl_ref: (ROWS, LANES) int32 scratch."""
+    (len 0 → pad); incl_ref: (ROWS, LANES) int32 and acc_ref: (8, CAP_PAD)
+    float32 scratch."""
     a = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
     b = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
     tri = jnp.where(a <= b, 1.0, 0.0).astype(jnp.bfloat16)
-    words_iota = jax.lax.broadcasted_iota(jnp.int32, (CAP_PAD, LANES), 0)
+    win_iota = jax.lax.broadcasted_iota(jnp.int32, (WIN, LANES), 0)
     plane = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
 
     def block(g, carry):
@@ -65,9 +72,9 @@ def _pack_kernel(codes_ref, lens_ref, words_ref, bits_ref, incl_ref, *,
         incl_ref[...] = jnp.dot(lens.astype(jnp.bfloat16), tri,
                                 preferred_element_type=jnp.float32
                                 ).astype(jnp.int32)   # in-row prefix sums
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def row(r, acc_run):
-            acc, run = acc_run                        # run: bits before row
+        def row(r, run):                              # run: bits before row
             v = codes_ref[pl.ds(first + r, 1), :]
             l = lens_ref[pl.ds(first + r, 1), :]
             incl = incl_ref[pl.ds(r, 1), :]
@@ -79,23 +86,22 @@ def _pack_kernel(codes_ref, lens_ref, words_ref, bits_ref, incl_ref, *,
             lo = jnp.where(sh < 0, v << jnp.clip(32 + sh, 0, 31), 0)
             part = jnp.where(plane < 4, hi, lo)       # (8, L) byte planes
             byte = _srl(part, (plane & 3) * 8) & 0xFF
-            onehot = jnp.where(words_iota == (offs >> 5), 1.0, 0.0)
-            acc += jax.lax.dot_general(
+            base = pl.multiple_of((run >> 5) & ~(LANES - 1), LANES)
+            onehot = jnp.where(win_iota == (offs >> 5) - base, 1.0, 0.0)
+            acc_ref[:, pl.ds(base, WIN)] += jax.lax.dot_general(
                 byte.astype(jnp.float32).astype(jnp.bfloat16),
                 onehot.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)   # (8, CAP_PAD)
-            return acc, run + incl[:, LANES - 1:LANES]
+                preferred_element_type=jnp.float32)   # (8, WIN)
+            return run + jnp.sum(l)
 
-        acc, bits = jax.lax.fori_loop(
-            0, ROWS, row, (jnp.zeros((8, CAP_PAD), jnp.float32),
-                           jnp.zeros((1, 1), jnp.int32)))
-        acc = acc.astype(jnp.int32)
+        bits = jax.lax.fori_loop(0, ROWS, row, jnp.int32(0), unroll=True)
+        acc = acc_ref[...].astype(jnp.int32)
         hi_w = acc[0:1] | (acc[1:2] << 8) | (acc[2:3] << 16) | (acc[3:4] << 24)
         lo_w = acc[4:5] | (acc[5:6] << 8) | (acc[6:7] << 16) | (acc[7:8] << 24)
         # The last word's low part never exists (CAP_PAD > CAP_WORDS), so
         # the roll's wrap-around carries a zero into word 0.
         words_ref[pl.ds(g, 1), :] = hi_w | pltpu.roll(lo_w, 1, 1)
-        bits_ref[pl.ds(g, 1), :] = jnp.broadcast_to(bits, (1, LANES))
+        bits_ref[pl.ds(g, 1), :] = jnp.full((1, LANES), bits, jnp.int32)
         return carry
 
     jax.lax.fori_loop(0, g_step, block, 0)
@@ -118,6 +124,7 @@ def pack_blocks_pallas(codes: jnp.ndarray, lens: jnp.ndarray, *,
     c = jnp.pad(codes.astype(jnp.int32), (0, pad)).reshape(-1, LANES)
     l = jnp.pad(lens.astype(jnp.int32), (0, pad)).reshape(-1, LANES)
 
+    vma = jax.typeof(c).vma     # varying like its input under shard_map
     words, bits = pl.pallas_call(
         functools.partial(_pack_kernel, g_step=g),
         grid=(nb_pad // g,),
@@ -125,9 +132,10 @@ def pack_blocks_pallas(codes: jnp.ndarray, lens: jnp.ndarray, *,
                   pl.BlockSpec((g * ROWS, LANES), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((g, CAP_PAD), lambda i: (i, 0)),
                    pl.BlockSpec((g, LANES), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb_pad, CAP_PAD), jnp.int32),
-                   jax.ShapeDtypeStruct((nb_pad, LANES), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((ROWS, LANES), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((nb_pad, CAP_PAD), jnp.int32, vma=vma),
+                   jax.ShapeDtypeStruct((nb_pad, LANES), jnp.int32, vma=vma)],
+        scratch_shapes=[pltpu.VMEM((ROWS, LANES), jnp.int32),
+                        pltpu.VMEM((8, CAP_PAD), jnp.float32)],
         interpret=resolve_interpret(interpret),
     )(c, l)
     words = jax.lax.bitcast_convert_type(words[:nb, :CAP_WORDS], jnp.uint32)

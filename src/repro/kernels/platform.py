@@ -2,7 +2,9 @@
 
 One rule for every kernel entry point, applied when the call is traced
 (never at import): interpret only when the backend running the call is
-the CPU.  A TPU never interprets.
+the CPU.  A TPU never interprets.  Code that picks between a kernel and
+an XLA formulation of the same result asks ``compiles_with_mosaic``, so
+it takes the kernel only where the kernel compiles.
 """
 from __future__ import annotations
 
@@ -23,3 +25,9 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
             f"Pallas interpret mode requested on backend "
             f"{jax.default_backend()!r}; kernels interpret only on the CPU")
     return bool(interpret)
+
+
+def compiles_with_mosaic(backend: str) -> bool:
+    """Whether the kernels compile through Mosaic on ``backend``: on a
+    TPU only (the CPU interprets them)."""
+    return backend == "tpu"

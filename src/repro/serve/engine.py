@@ -18,7 +18,7 @@ import numpy as np
 from ..comm.compression import CompressionSpec, payload_stats
 from ..core.codec import get_codec
 from ..core.encoder import (DEFAULT_CHUNK, chunk_counts_for, concat_chunks,
-                            encode_chunked_jit)
+                            encode_chunked_jit, pack_path)
 from ..models.common import ModelConfig
 from ..models.transformer import decode_step, prefill
 
@@ -54,7 +54,10 @@ def make_serve_step(model_cfg: ModelConfig,
     encode → chunked decode at the spec's chunk size — and accounts it:
     decoded payload bits, chunk count (the streaming granularity a
     receiving peer overlaps), and a decode-mismatch counter that must
-    stay 0 (losslessness observed in production, not assumed).  The
+    stay 0 (losslessness observed in production, not assumed), and
+    ``act_pack_kernel_chunks``, the chunks the bit-pack kernel packed
+    (``core.encoder.pack_path``: all of them on a TPU at chunk 2048, none
+    on the CPU).  The
     decode tables are rebuilt from the spec's canonical length vectors
     at trace time — exactly what a receiving node holds — and the
     decode runs the spec's ``decode_backend`` (scan / pallas /
@@ -86,7 +89,8 @@ def make_serve_step(model_cfg: ModelConfig,
         metrics = {"act_raw_bits": z, "act_coded_bits": z,
                    "act_wire_raw_bits": z, "act_wire_coded_bits": z,
                    "act_decoded_bits": z, "act_decode_chunks": z,
-                   "act_decode_mismatch": z, "moe_wire_raw_bits": z}
+                   "act_decode_mismatch": z, "act_pack_kernel_chunks": z,
+                   "moe_wire_raw_bits": z}
         if (comp_spec is not None and comp_spec.enabled
                 and ep_degree > 1 and n_moe):
             from ..comm.transport import RING_FACTORS, moe_dispatch_raw_bits
@@ -131,6 +135,10 @@ def make_serve_step(model_cfg: ModelConfig,
                     metrics["act_decoded_bits"] += bits.sum().astype(
                         jnp.float32)
                     metrics["act_decode_chunks"] += jnp.float32(len(counts))
+                    if pack_path(decode_chunk,
+                                 jax.default_backend()) == "kernel":
+                        metrics["act_pack_kernel_chunks"] += jnp.float32(
+                            len(counts))
                     metrics["act_decode_mismatch"] += (
                         dec != sym.astype(jnp.uint8)).sum().astype(jnp.float32)
         return logits, caches, metrics
@@ -299,7 +307,8 @@ class Engine:
         for k in ("act_raw_bits", "act_coded_bits", "act_shannon_bits",
                   "act_wire_raw_bits", "act_wire_coded_bits",
                   "act_decoded_bits", "act_decode_chunks",
-                  "act_decode_mismatch", "moe_wire_raw_bits", "book_epoch"):
+                  "act_decode_mismatch", "act_pack_kernel_chunks",
+                  "moe_wire_raw_bits", "book_epoch"):
             totals.setdefault(k, 0.0)                  # stable for 1-token gens
         totals.update(self.hbm_stats())
         return np.concatenate([np.asarray(t) for t in out], axis=1), totals

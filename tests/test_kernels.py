@@ -128,3 +128,56 @@ class TestBitpackKernel:
         assert int(got_bits) == int(want_bits)
         out = decode_np(np.asarray(got_words), n, book)
         assert (out == np.asarray(sym)).all()
+
+    @staticmethod
+    def _rows(case):
+        """(v, l, cap) rows as the chunked encoders hand them to
+        ``_pack_rows``: a flat stream with a partial tail chunk, or
+        recoded blocks with ragged counts (one of them 0), under a
+        Huffman, a QLC or an all-16-bit book."""
+        from repro.core import encoder
+        from repro.core.qlc import build_qlc_book
+        from repro.kernels.bitpack import BLOCK
+        layout, book_kind = case.split("-")
+        rng = np.random.default_rng(0)
+        counts = np.maximum(rng.integers(0, 1000, size=256), 1)
+        counts[:4] = 10 ** 6                    # skew: short and long codes
+        if book_kind == "huffman":
+            book = build_codebook(counts, codec="huffman")
+            codes, lengths = book.codes, book.lengths
+        elif book_kind == "qlc":
+            book = build_qlc_book(counts)
+            codes, lengths = book.codes, book.lengths
+        else:                                   # every code 16 bits wide
+            codes = rng.integers(0, 1 << 16, size=256)
+            lengths = np.full(256, 16)
+        codes = jnp.asarray(np.asarray(codes, np.uint32))
+        lengths = jnp.asarray(np.asarray(lengths, np.int32))
+        if layout == "flat":
+            sym = _sym(1, 3 * BLOCK + 517)
+            v, l = encoder._chunk_rows(sym, codes, lengths, BLOCK)
+        else:
+            blocks = _sym(2, 5 * BLOCK).reshape(5, BLOCK)
+            ragged = jnp.asarray([BLOCK, 0, 700, 1, BLOCK], jnp.int32)
+            v, l = encoder._block_rows(blocks, ragged, codes, lengths)
+        return v, l, encoder.chunk_capacity_words(BLOCK)
+
+    @pytest.mark.parametrize("case", ["flat-huffman", "flat-qlc",
+                                      "ragged-huffman", "ragged-qlc",
+                                      "flat-all16", "ragged-all16"])
+    def test_pack_rows_kernel_matches_scatter(self, case):
+        from repro.core import encoder
+        v, l, cap = self._rows(case)
+        got_words, got_bits = encoder._pack_rows_kernel(v, l, cap)
+        want_words, want_bits = encoder._pack_rows_xla(v, l, cap)
+        assert got_words.shape == want_words.shape == (v.shape[0], cap)
+        assert (np.asarray(got_bits) == np.asarray(want_bits)).all()
+        assert (np.asarray(got_words) == np.asarray(want_words)).all()
+
+    @pytest.mark.parametrize("backend,width,path", [
+        ("tpu", 2048, "kernel"), ("cpu", 2048, "xla"), ("gpu", 2048, "xla"),
+        ("tpu", 1024, "xla"), ("tpu", 4096, "xla"), ("tpu", 64, "xla")])
+    def test_pack_path_rule(self, backend, width, path):
+        from repro.core.encoder import pack_path
+        assert pack_path(width, backend) == path
+

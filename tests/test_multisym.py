@@ -299,3 +299,4 @@ class TestServeVerifyBackend:
         _, _, m = step(params, tokens[:, -1:], caches, jnp.int32(4))
         assert float(m["act_decode_mismatch"]) == 0.0
         assert float(m["act_decoded_bits"]) > 0.0
+        assert float(m["act_pack_kernel_chunks"]) == 0.0   # CPU: no kernel

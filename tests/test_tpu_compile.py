@@ -7,8 +7,9 @@ against ``ShapeDtypeStruct`` arguments placed on one described v5e chip
 and compiles it, which raises whatever Mosaic would refuse on the chip
 (unaligned blocks, vector gathers, unsupported reshapes).
 
-Widths: 4M symbols in chunks of 2048 for the encode/pack/decode kernels,
-and qwen3-4b's ``w_down`` (9728 × 2560, one chunk per weight row) for the
+Widths: 4M symbols in chunks of 2048 for the encode/decode kernels, the
+serve cell's logits (16 × 151936 symbols, 1187 chunks of 2048) for the
+bit-pack kernel as the chunked encoders call it, and qwen3-4b's ``w_down`` (9728 × 2560, one chunk per weight row) for the
 fused decode-matmul.  The topology is described inside a fixture, never
 at import, so every test worker collects the same tests.
 """
@@ -19,9 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.encoder import DEFAULT_CHUNK, chunk_capacity_words
+from repro.core.encoder import (DEFAULT_CHUNK, _pack_rows_kernel,
+                                chunk_capacity_words)
 from repro.core.huffman import MAX_CODE_LEN, MULTISYM_K, MULTISYM_SMAX
-from repro.kernels.bitpack import pack_blocks_pallas
 from repro.kernels.decode import (decode_chunks_multisym_pallas,
                                   decode_chunks_pallas,
                                   decode_chunks_qlc_pallas)
@@ -36,6 +37,7 @@ CAP = chunk_capacity_words(DEFAULT_CHUNK)
 TLEN = MAX_CODE_LEN + 1
 W_DOWN = (9728, 2560)           # qwen3-4b FFN down projection (K, N)
 M = 8                           # decode-step rows of x
+SERVE_NB = -(-16 * 151936 // DEFAULT_CHUNK)   # qwen3-4b logits a step
 
 
 @pytest.fixture(scope="module")
@@ -94,9 +96,11 @@ def test_encode_lookup(spec):
 
 
 def test_bitpack(spec):
-    compiles_with_mosaic(
-        lambda c, l: pack_blocks_pallas(c, l, interpret=False),
-        spec((N_SYMBOLS,), jnp.uint32), spec((N_SYMBOLS,), jnp.int32))
+    rows = spec((SERVE_NB, DEFAULT_CHUNK), jnp.uint32)
+    fn = jax.jit(lambda v, l: _pack_rows_kernel(v, l, CAP, interpret=False))
+    words, bits = jax.eval_shape(fn, rows, rows)
+    assert words.shape == (SERVE_NB, CAP) and bits.shape == (SERVE_NB,)
+    compiles_with_mosaic(fn, rows, rows)
 
 
 def _canonical_tables(spec, planes=()):

@@ -113,12 +113,12 @@ def _planes(x):
 
 # ---------------------------------------------------------------- phases
 def phase_kernels(seed: int, n: int = KERNEL_SYMBOLS) -> dict:
-    """Histogram, LUT-encode and bit-pack kernels vs their oracles."""
+    """Histogram, LUT-encode and lookup-and-pack kernels vs their oracles."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.core.encoder import (_chunk_rows, _pack_rows_xla,
-                                    encode_chunked_jit)
+    from repro.core.encoder import (_chunk_rows, _masked_rows,
+                                    _pack_rows_xla, encode_chunked_jit)
     from repro.kernels import bitpack, ref
     from repro.kernels.encode import encode_lookup_pallas
     from repro.kernels.histogram import histogram256_pallas
@@ -135,14 +135,14 @@ def phase_kernels(seed: int, n: int = KERNEL_SYMBOLS) -> dict:
     wc, wl, wb = ref.encode_lookup_ref(sym, lut)
     check(bool(jnp.array_equal(codes, wc)) and bool(jnp.array_equal(lens, wl))
           and int(bits) == int(wb), "LUT-encode kernel != gather oracle")
-    words, block_bits = bitpack.pack_blocks_pallas(codes, lens)
     book_codes, book_lens = jnp.asarray(book.codes), jnp.asarray(book.lengths)
+    words, block_bits = bitpack.lookup_pack_pallas(sym, book_codes, book_lens)
     want_w, want_b = _pack_rows_xla(
-        *_chunk_rows(sym, book_codes, book_lens, bitpack.BLOCK),
-        bitpack.CAP_WORDS)
+        *_masked_rows(*_chunk_rows(sym, bitpack.BLOCK), book_codes,
+                      book_lens), bitpack.CAP_WORDS)
     check(bool(jnp.array_equal(words, want_w))
           and bool(jnp.array_equal(block_bits, want_b)),
-          "bit-pack kernel != scatter-add packing")
+          "lookup-and-pack kernel != gather and scatter-add packing")
     enc_w, enc_b = encode_chunked_jit(sym, book_codes, book_lens,
                                       chunk=bitpack.BLOCK)
     check(bool(jnp.array_equal(enc_w, want_w))

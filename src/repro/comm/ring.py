@@ -38,7 +38,7 @@ by ``decode_backend``, resolved per codec by ``transport.decode_blocks``
 (huffman: ``scan`` / ``pallas`` / ``multisym`` / ``multisym_pallas``;
 qlc: ``scan`` / ``pallas`` — ``"auto"`` picks the codec's default, see
 docs/codecs.md).  The encode side is codec-agnostic: both codecs pack
-through the same ``_pack_rows`` core, so the hop recode path is
+through the same ``_encode_rows`` core, so the hop recode path is
 unchanged.
 
 Numerics: gather-type ops (all_gather, all_to_all) forward values

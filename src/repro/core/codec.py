@@ -22,7 +22,7 @@ Built-ins:
       traffic for a large symbols/sec win on the ring hop path.
 
 Both codecs share the wire format end-to-end: books expose
-``codes`` / ``lengths`` / ``max_len`` so the single ``_pack_rows``
+``codes`` / ``lengths`` / ``max_len`` so the single ``_encode_rows``
 encode core packs either, and every book's ``max_len`` is bounded by
 ``MAX_CODE_LEN`` so ``chunk_capacity_words`` is codec-independent —
 a spec can switch codecs without touching buffer shapes.

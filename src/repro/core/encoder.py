@@ -112,48 +112,63 @@ class EncodeResult:
 # jit bit-packing encoder
 # --------------------------------------------------------------------------
 def pack_path(width: int, backend: str) -> str:
-    """How ``_pack_rows`` places codeword parts into words, for rows of
-    ``width`` codes on ``backend``: ``"kernel"`` — the Mosaic bit-pack
-    kernel (``kernels.bitpack``) — where the rows are its BLOCK wide and
-    the backend compiles it (a TPU); ``"xla"`` — two scatter-adds —
-    everywhere else.  Both give the same words, bit for bit."""
+    """How ``_encode_rows`` looks up and packs rows of ``width`` symbols
+    on ``backend``: ``"kernel"`` — the Mosaic lookup-and-pack kernel
+    (``kernels.bitpack``) — where the rows are its BLOCK wide and the
+    backend compiles it (a TPU); ``"xla"`` — a gather of the book and
+    two scatter-adds — everywhere else.  Both give the same words, bit
+    for bit."""
     from ..kernels.bitpack import BLOCK
     from ..kernels.platform import compiles_with_mosaic
     return ("kernel" if width == BLOCK and compiles_with_mosaic(backend)
             else "xla")
 
 
-def _pack_rows(v: jnp.ndarray, l: jnp.ndarray, cap: int
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Shared bit-pack core: per-row MSB-first packing.
+def _encode_rows(sym: jnp.ndarray, valid: jnp.ndarray, codes: jnp.ndarray,
+                 lengths: jnp.ndarray, cap: int
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Shared encode core: per-row codebook lookup and MSB-first packing.
 
-    v: (NB, C) uint32 right-aligned codewords; l: (NB, C) uint32 lengths
-    (0 ⇒ the slot contributes no bits).  Returns (words (NB, cap) uint32,
-    bits (NB,) int32), through the path ``pack_path`` picks for the
-    backend tracing the call.
+    sym: (NB, C) int32 symbols; valid: (NB, C) bool (False ⇒ the slot
+    carries no symbol and contributes no bits).  Returns (words (NB, cap)
+    uint32, bits (NB,) int32), through the path ``pack_path`` picks for
+    the backend tracing the call.
     """
     from ..kernels.bitpack import CAP_WORDS
     if (cap <= CAP_WORDS
-            and pack_path(v.shape[1], jax.default_backend()) == "kernel"):
-        return _pack_rows_kernel(v, l, cap)
-    return _pack_rows_xla(v, l, cap)
+            and pack_path(sym.shape[1], jax.default_backend()) == "kernel"):
+        return _encode_rows_kernel(sym, valid, codes, lengths, cap)
+    return _pack_rows_xla(*_masked_rows(sym, valid, codes, lengths), cap)
 
 
-def _pack_rows_kernel(v: jnp.ndarray, l: jnp.ndarray, cap: int, *,
-                      interpret: bool | None = None
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``_pack_rows`` by the bit-pack kernel: rows of ``bitpack.BLOCK``
-    codes are its blocks, and ``cap`` ≤ its ``CAP_WORDS`` (codes ≤ 16
-    bits) words hold every row's bits."""
-    from ..kernels.bitpack import pack_blocks_pallas
-    words, bits = pack_blocks_pallas(v.reshape(-1), l.reshape(-1),
-                                     interpret=interpret)
+def _encode_rows_kernel(sym: jnp.ndarray, valid: jnp.ndarray,
+                        codes: jnp.ndarray, lengths: jnp.ndarray, cap: int,
+                        *, interpret: bool | None = None
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``_encode_rows`` by the lookup-and-pack kernel: rows of
+    ``bitpack.BLOCK`` symbols are its blocks, slots that carry none get
+    ``NO_SYMBOL``, and ``cap`` ≤ its ``CAP_WORDS`` (codes ≤ 16 bits)
+    words hold every row's bits."""
+    from ..kernels.bitpack import NO_SYMBOL, lookup_pack_pallas
+    words, bits = lookup_pack_pallas(
+        jnp.where(valid, sym, NO_SYMBOL).reshape(-1), codes, lengths,
+        interpret=interpret)
     return words[:, :cap], bits
+
+
+def _masked_rows(sym, valid, codes, lengths):
+    """The XLA path's lookup: (code, length) rows, 0 where not valid."""
+    v = codes[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
+    l = lengths[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
+    return v, l
 
 
 def _pack_rows_xla(v: jnp.ndarray, l: jnp.ndarray, cap: int
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``_pack_rows`` by two masked shifts and two scatter-adds.
+    """Packing by two masked shifts and two scatter-adds.
+
+    v: (NB, C) uint32 right-aligned codewords; l: (NB, C) uint32 lengths
+    (0 ⇒ the slot contributes no bits).
 
     A codeword of length ≤16 starting at bit offset o spans at most two
     32-bit words; high/low parts assemble via scatter-add — fields are
@@ -198,10 +213,9 @@ def encode_jit(symbols: jnp.ndarray, codes: jnp.ndarray, lengths: jnp.ndarray,
     n = symbols.shape[0]
     if n > _MAX_SYMBOLS:
         raise ValueError(f"chunk too large: {n} > {_MAX_SYMBOLS}")
-    sym = symbols.astype(jnp.int32)
-    v = codes[sym].astype(jnp.uint32)[None, :]
-    l = lengths[sym].astype(jnp.uint32)[None, :]
-    words, bits = _pack_rows(v, l, packed_words_capacity(n, max_len))
+    sym = symbols.astype(jnp.int32)[None, :]
+    words, bits = _encode_rows(sym, jnp.ones(sym.shape, bool), codes,
+                               lengths, packed_words_capacity(n, max_len))
     return words.reshape(-1), bits[0].astype(jnp.uint32)
 
 
@@ -304,18 +318,18 @@ def encode_chunked_jit(symbols: jnp.ndarray, codes: jnp.ndarray,
     Same bitfield math as ``encode_jit`` applied per chunk row: pad
     positions get length 0 (and code 0) so they contribute no bits.
     Safe under jit/shard_map; at chunk == bitpack.BLOCK on a TPU the
-    ``pack_blocks_pallas`` kernel packs the rows (``pack_path``), with
-    the same words.
+    ``lookup_pack_pallas`` kernel looks the rows up and packs them
+    (``pack_path``), with the same words.
 
     Returns (block_words (NB, cap) uint32, block_bits (NB,) int32).
     """
-    v, l = _chunk_rows(symbols, codes, lengths, chunk)
-    return _pack_rows(v, l, chunk_capacity_words(chunk, max_len))
+    return _encode_rows(*_chunk_rows(symbols, chunk), codes, lengths,
+                        chunk_capacity_words(chunk, max_len))
 
 
-def _chunk_rows(symbols, codes, lengths, chunk: int):
-    """A flat stream's (NB, chunk) code and length rows; pad slots of the
-    tail chunk get code 0 and length 0."""
+def _chunk_rows(symbols, chunk: int):
+    """A flat stream's (NB, chunk) symbol rows and their valid mask: the
+    pad slots of the tail chunk carry no symbol."""
     n = symbols.shape[0]
     if n > _MAX_SYMBOLS:
         raise ValueError(f"chunk too large: {n} > {_MAX_SYMBOLS}")
@@ -324,13 +338,7 @@ def _chunk_rows(symbols, codes, lengths, chunk: int):
     sym = jnp.pad(symbols.astype(jnp.int32), (0, pad)).reshape(nb, chunk)
     valid = (jnp.arange(chunk, dtype=jnp.int32)[None, :]
              + jnp.arange(nb, dtype=jnp.int32)[:, None] * chunk) < n
-    return _masked_rows(sym, valid, codes, lengths)
-
-
-def _masked_rows(sym, valid, codes, lengths):
-    v = codes[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
-    l = lengths[sym].astype(jnp.uint32) * valid.astype(jnp.uint32)
-    return v, l
+    return sym, valid
 
 
 @partial(jax.jit, static_argnames=("max_len",))
@@ -350,17 +358,18 @@ def recode_chunks_jit(sym_blocks: jnp.ndarray, chunk_counts: jnp.ndarray,
     sym_blocks: (NB, chunk) uint8/int32; chunk_counts: (NB,) int32.
     Returns (block_words (NB, cap) uint32, block_bits (NB,) int32).
     """
-    v, l = _block_rows(sym_blocks, chunk_counts, codes, lengths)
-    return _pack_rows(v, l, chunk_capacity_words(sym_blocks.shape[1], max_len))
+    return _encode_rows(*_block_rows(sym_blocks, chunk_counts), codes,
+                        lengths,
+                        chunk_capacity_words(sym_blocks.shape[1], max_len))
 
 
-def _block_rows(sym_blocks, chunk_counts, codes, lengths):
-    """Blocked symbols' code and length rows; slots past each chunk's
-    count get code 0 and length 0."""
+def _block_rows(sym_blocks, chunk_counts):
+    """Blocked symbols as int32 rows and their valid mask: slots past
+    each chunk's count carry no symbol."""
     chunk = sym_blocks.shape[1]
     valid = (jnp.arange(chunk, dtype=jnp.int32)[None, :]
              < chunk_counts.astype(jnp.int32)[:, None])
-    return _masked_rows(sym_blocks.astype(jnp.int32), valid, codes, lengths)
+    return sym_blocks.astype(jnp.int32), valid
 
 
 @partial(jax.jit, static_argnames=("chunk", "max_len"))

@@ -31,7 +31,7 @@ identical book from a ``CompressionSpec``'s lengths, mirroring what
 canonical ordering does for Huffman books (see ``serve.engine``).
 
 Wire format: identical to Huffman — codes ride the shared
-``_pack_rows`` bit-pack core (MSB-first, 32-bit words) and
+``_encode_rows`` lookup-and-pack core (MSB-first, 32-bit words) and
 ``max_len`` stays ``MAX_CODE_LEN`` so ``chunk_capacity_words`` and the
 chunked-stream capacity are byte-compatible across codecs; only the
 (codes, lengths) LUT and the decoder differ.

@@ -68,13 +68,15 @@ def _encode_kernel(sym_ref, lut_ref, code_ref, len_ref, bits_ref):
     bits_ref[...] = jax.lax.fori_loop(0, ROWS, row, bits_ref[...])
 
 
-def lut_rows(lut: jnp.ndarray) -> jnp.ndarray:
-    """(256, 2) [codeword, length] LUT → the kernel's (8, 256) bf16
-    byte-split transpose: every entry a byte, hence exact in bf16."""
-    code = lut[:, 0].astype(jnp.int32)
-    rows = jnp.stack([code & 0xFF, code >> 8, lut[:, 1].astype(jnp.int32)])
+def lut_rows(codes: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
+    """A book's (n ≤ 256,) codewords and lengths → the kernels' (8, 256)
+    bf16 byte-split transposed LUT; columns past n stay 0.  Every entry
+    is a byte (codes ≤ 16 bits), hence exact in bf16."""
+    code = codes.astype(jnp.int32)
+    rows = jnp.stack([code & 0xFF, code >> 8, lengths.astype(jnp.int32)])
     out = jnp.zeros((LUT_ROWS, N_SYMBOLS), jnp.float32)
-    return out.at[:3].set(rows.astype(jnp.float32)).astype(jnp.bfloat16)
+    return out.at[:3, :code.shape[0]].set(rows.astype(jnp.float32)
+                                          ).astype(jnp.bfloat16)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -111,7 +113,7 @@ def encode_lookup_pallas(symbols: jnp.ndarray, lut: jnp.ndarray, *,
             jax.ShapeDtypeStruct((1, LANES), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
-    )(sym, lut_rows(lut))
+    )(sym, lut_rows(lut[:, 0], lut[:, 1]))
 
     total_bits = bits.sum()
     if pad:

@@ -66,7 +66,7 @@ def message_bits(symbols: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
 
 
 def merge_block_streams(block_words, block_bits) -> "tuple":
-    """Stitch per-block bitstreams (from pack_blocks_pallas) into one
+    """Stitch per-block bitstreams (from lookup_pack_pallas) into one
     contiguous MSB-first stream.  One barrel shift per block — the
     transmit-FIFO side of the split (host/jnp; O(total words))."""
     import numpy as np
@@ -99,12 +99,13 @@ def merge_block_streams(block_words, block_bits) -> "tuple":
 
 
 def pack_with_book(symbols, book):
-    """Full kernel-path encode: LUT kernel → block-pack kernel → merge.
+    """Full kernel-path encode: lookup-and-pack kernel → merge.
     Bit-exact with core.encoder.encode_jit (tested)."""
-    from .bitpack import pack_blocks_pallas
+    from .bitpack import lookup_pack_pallas
 
-    codes, lens, _ = encode_lookup(symbols, jnp.asarray(book.code_lut()))
-    words, bits = pack_blocks_pallas(codes, lens)
+    words, bits = lookup_pack_pallas(symbols.reshape(-1),
+                                     jnp.asarray(book.codes),
+                                     jnp.asarray(book.lengths))
     return merge_block_streams(words, bits)
 
 
@@ -113,7 +114,7 @@ def decode_chunks(block_words, chunk_counts, book: Codebook, *,
     """Chunked canonical decode via the Pallas kernel.
 
     block_words (NB, cap) uint32, chunk_counts (NB,) int32 → (NB, chunk)
-    int32 symbols, zero past each count.  Inverse of pack_blocks_pallas /
+    int32 symbols, zero past each count.  Inverse of lookup_pack_pallas /
     encode_chunked_jit chunk streams under the same codebook.
     """
     from .decode import decode_chunks_pallas
@@ -197,7 +198,7 @@ def decode_with_book_kernel(symbols_stream, book: Codebook, n_symbols: int, *,
     """Decode a kernel-path chunked stream back to (n_symbols,) uint8.
 
     symbols_stream is the (block_words, block_bits) pair produced by
-    pack_blocks_pallas (block_bits is unused for decoding — the walk is
+    lookup_pack_pallas (block_bits is unused for decoding — the walk is
     symbol-counted — but belongs to the wire format as the per-chunk
     header).
     """

@@ -55,9 +55,9 @@ def make_serve_step(model_cfg: ModelConfig,
     decoded payload bits, chunk count (the streaming granularity a
     receiving peer overlaps), and a decode-mismatch counter that must
     stay 0 (losslessness observed in production, not assumed), and
-    ``act_pack_kernel_chunks``, the chunks the bit-pack kernel packed
-    (``core.encoder.pack_path``: all of them on a TPU at chunk 2048, none
-    on the CPU).  The
+    ``act_pack_kernel_chunks``, the chunks the bit-pack kernel both
+    looked up and packed (``core.encoder.pack_path``: all of them on a
+    TPU at chunk 2048, none on the CPU).  The
     decode tables are rebuilt from the spec's canonical length vectors
     at trace time — exactly what a receiving node holds — and the
     decode runs the spec's ``decode_backend`` (scan / pallas /

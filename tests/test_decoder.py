@@ -144,10 +144,9 @@ class TestChunkedFormat:
         rng = np.random.default_rng(17)
         sym = rng.integers(0, 256, size=5000).astype(np.uint8)
         book = _book_from(sym)
-        from repro.kernels.bitpack import pack_blocks_pallas
-        codes, lens, _ = ops.encode_lookup(jnp.asarray(sym),
-                                           jnp.asarray(book.code_lut()))
-        kw, kb = pack_blocks_pallas(codes, lens)
+        from repro.kernels.bitpack import lookup_pack_pallas
+        kw, kb = lookup_pack_pallas(jnp.asarray(sym), jnp.asarray(book.codes),
+                                    jnp.asarray(book.lengths))
         stream = encode_chunked(jnp.asarray(sym), book)
         assert (np.asarray(kw) == np.asarray(stream.block_words)).all()
         assert (np.asarray(kb) == np.asarray(stream.block_bits)).all()

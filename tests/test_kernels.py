@@ -1,4 +1,5 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs jnp oracle."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -131,9 +132,10 @@ class TestBitpackKernel:
 
     @staticmethod
     def _rows(case):
-        """(v, l, cap) rows as the chunked encoders hand them to
-        ``_pack_rows``: a flat stream with a partial tail chunk, or
-        recoded blocks with ragged counts (one of them 0), under a
+        """(sym, valid, codes, lengths, cap) as the chunked encoders hand
+        them to ``_encode_rows``: a flat stream with a partial tail chunk,
+        recoded blocks with ragged counts (one of them 0, one 1), or a
+        flat stream that hits every one of the 256 book entries, under a
         Huffman, a QLC or an all-16-bit book."""
         from repro.core import encoder
         from repro.core.qlc import build_qlc_book
@@ -154,25 +156,64 @@ class TestBitpackKernel:
         codes = jnp.asarray(np.asarray(codes, np.uint32))
         lengths = jnp.asarray(np.asarray(lengths, np.int32))
         if layout == "flat":
-            sym = _sym(1, 3 * BLOCK + 517)
-            v, l = encoder._chunk_rows(sym, codes, lengths, BLOCK)
+            sym, valid = encoder._chunk_rows(_sym(1, 3 * BLOCK + 517), BLOCK)
+        elif layout == "allsym":
+            every = rng.permutation(np.tile(np.arange(256), 2 * BLOCK // 256))
+            sym, valid = encoder._chunk_rows(jnp.asarray(every, jnp.uint8),
+                                             BLOCK)
+            assert set(np.asarray(sym).ravel()) == set(range(256))
         else:
             blocks = _sym(2, 5 * BLOCK).reshape(5, BLOCK)
             ragged = jnp.asarray([BLOCK, 0, 700, 1, BLOCK], jnp.int32)
-            v, l = encoder._block_rows(blocks, ragged, codes, lengths)
-        return v, l, encoder.chunk_capacity_words(BLOCK)
+            sym, valid = encoder._block_rows(blocks, ragged)
+        return sym, valid, codes, lengths, encoder.chunk_capacity_words(BLOCK)
 
     @pytest.mark.parametrize("case", ["flat-huffman", "flat-qlc",
                                       "ragged-huffman", "ragged-qlc",
-                                      "flat-all16", "ragged-all16"])
+                                      "flat-all16", "ragged-all16",
+                                      "allsym-huffman", "allsym-qlc",
+                                      "allsym-all16"])
     def test_pack_rows_kernel_matches_scatter(self, case):
+        """The lookup-and-pack kernel against the gather and scatter-add
+        path, words and bits bit for bit."""
         from repro.core import encoder
-        v, l, cap = self._rows(case)
-        got_words, got_bits = encoder._pack_rows_kernel(v, l, cap)
-        want_words, want_bits = encoder._pack_rows_xla(v, l, cap)
-        assert got_words.shape == want_words.shape == (v.shape[0], cap)
+        sym, valid, codes, lengths, cap = self._rows(case)
+        got_words, got_bits = encoder._encode_rows_kernel(
+            sym, valid, codes, lengths, cap, interpret=True)
+        want_words, want_bits = encoder._pack_rows_xla(
+            *encoder._masked_rows(sym, valid, codes, lengths), cap)
+        assert got_words.shape == want_words.shape == (sym.shape[0], cap)
         assert (np.asarray(got_bits) == np.asarray(want_bits)).all()
         assert (np.asarray(got_words) == np.asarray(want_words)).all()
+
+    @pytest.mark.parametrize("entry", ["encode_chunked_jit",
+                                       "recode_chunks_jit"])
+    def test_chunked_encoders_route_alike(self, entry, monkeypatch):
+        """The chunked encoders give the same words whichever path
+        ``pack_path`` picks: the kernel path forced here runs the
+        lookup-and-pack kernel in interpret mode."""
+        from repro.core import encoder
+        from repro.kernels.bitpack import BLOCK
+        book, _ = _lut(3)
+        codes, lengths = jnp.asarray(book.codes), jnp.asarray(book.lengths)
+        fn = getattr(encoder, entry).__wrapped__     # past jit's caches
+        kw = {}
+        if entry == "encode_chunked_jit":
+            args, kw = (_sym(3, 2 * BLOCK + 300), codes, lengths), {
+                "chunk": BLOCK}
+        else:
+            counts = jnp.asarray([BLOCK, 1, 0], jnp.int32)
+            args = (_sym(4, 3 * BLOCK).reshape(3, BLOCK), counts, codes,
+                    lengths)
+        out = {}
+        for path in ("xla", "kernel"):
+            monkeypatch.setattr(encoder, "pack_path", lambda w, b: path)
+            call = jax.jit(lambda *a: fn(*a, **kw))
+            traced = str(jax.make_jaxpr(call)(*args))
+            assert ("pallas_call" in traced) == (path == "kernel")
+            out[path] = call(*args)
+        assert (np.asarray(out["kernel"][0]) == np.asarray(out["xla"][0])).all()
+        assert (np.asarray(out["kernel"][1]) == np.asarray(out["xla"][1])).all()
 
     @pytest.mark.parametrize("backend,width,path", [
         ("tpu", 2048, "kernel"), ("cpu", 2048, "xla"), ("gpu", 2048, "xla"),

@@ -9,7 +9,7 @@ and compiles it, which raises whatever Mosaic would refuse on the chip
 
 Widths: 4M symbols in chunks of 2048 for the encode/decode kernels, the
 serve cell's logits (16 × 151936 symbols, 1187 chunks of 2048) for the
-bit-pack kernel as the chunked encoders call it, and qwen3-4b's ``w_down`` (9728 × 2560, one chunk per weight row) for the
+lookup-and-pack kernel as the chunked encoders call it, and qwen3-4b's ``w_down`` (9728 × 2560, one chunk per weight row) for the
 fused decode-matmul.  The topology is described inside a fixture, never
 at import, so every test worker collects the same tests.
 """
@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.encoder import (DEFAULT_CHUNK, _pack_rows_kernel,
-                                chunk_capacity_words)
+from repro.core.encoder import (DEFAULT_CHUNK, _block_rows, _chunk_rows,
+                                _encode_rows_kernel, chunk_capacity_words)
 from repro.core.huffman import MAX_CODE_LEN, MULTISYM_K, MULTISYM_SMAX
 from repro.kernels.decode import (decode_chunks_multisym_pallas,
                                   decode_chunks_pallas,
@@ -95,12 +95,31 @@ def test_encode_lookup(spec):
         spec((N_SYMBOLS,), jnp.uint8), spec((256, 2), jnp.uint32))
 
 
+def _book(spec):
+    return spec((256,), jnp.uint32), spec((256,), jnp.int32)
+
+
 def test_bitpack(spec):
-    rows = spec((SERVE_NB, DEFAULT_CHUNK), jnp.uint32)
-    fn = jax.jit(lambda v, l: _pack_rows_kernel(v, l, CAP, interpret=False))
-    words, bits = jax.eval_shape(fn, rows, rows)
+    """The lookup-and-pack kernel as ``encode_chunked_jit`` calls it on a
+    TPU, at the serve cell's plane: 1187 rows of 2048 symbols."""
+    fn = jax.jit(lambda s, c, l: _encode_rows_kernel(
+        *_chunk_rows(s, DEFAULT_CHUNK), c, l, CAP, interpret=False))
+    sym = spec((SERVE_NB * DEFAULT_CHUNK,), jnp.uint8)
+    words, bits = jax.eval_shape(fn, sym, *_book(spec))
     assert words.shape == (SERVE_NB, CAP) and bits.shape == (SERVE_NB,)
-    compiles_with_mosaic(fn, rows, rows)
+    compiles_with_mosaic(fn, sym, *_book(spec))
+
+
+def test_bitpack_recode(spec):
+    """The same kernel as ``recode_chunks_jit`` calls it: blocked symbols
+    with a count a block."""
+    fn = jax.jit(lambda s, n, c, l: _encode_rows_kernel(
+        *_block_rows(s, n), c, l, CAP, interpret=False))
+    args = (spec((SERVE_NB, DEFAULT_CHUNK), jnp.int32),
+            spec((SERVE_NB,), jnp.int32), *_book(spec))
+    words, bits = jax.eval_shape(fn, *args)
+    assert words.shape == (SERVE_NB, CAP) and bits.shape == (SERVE_NB,)
+    compiles_with_mosaic(fn, *args)
 
 
 def _canonical_tables(spec, planes=()):
